@@ -33,7 +33,8 @@ from repro.core.cluster import Cluster, Instance
 from repro.core.datastore import DataStore
 from repro.core.heartbeat import Clock, FailureDetector
 from repro.core.modelstate import ModelRegistry
-from repro.core.planner import PlanRequest, PlannerState, get_planner
+from repro.core.planner import (PlanRequest, PlannerState, get_planner,
+                                resolve_backend)
 from repro.core.variants import Application, Variant
 
 POLICIES = ("faillite", "full-warm", "full-cold", "full-warm-k")
@@ -340,7 +341,8 @@ class FailLiteController:
         # legacy `use_ilp` flag maps onto the "ilp" planner.
         # backend/coordinator knobs only apply to the greedy family —
         # other policies (ilp, load-aware, ...) ignore them.
-        self.planner_backend = planner_backend
+        self.planner_backend = resolve_backend(planner_backend,
+                                               planner_dtype)
         self.planner_coordinators = int(planner_coordinators)
         self.planner = self._resolve_planner(
             planner or ("ilp" if use_ilp else "greedy"))

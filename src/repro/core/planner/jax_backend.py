@@ -239,7 +239,7 @@ def plan_greedy_jax(apps: List[Application], cluster=None, *,
     site_index) are the caller's responsibility: the planner policies
     route such requests to the numpy path."""
     assert have_jax(), "jax backend requested but jax is not importable"
-    from jax.experimental import enable_x64
+    import jax
 
     t0 = time.time()
     exclude = exclude or {}
@@ -309,7 +309,7 @@ def plan_greedy_jax(apps: List[Application], cluster=None, *,
 
     dmc_all = _cmp_thresholds(dm_all, state.dtype)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         import jax.numpy as jnp
         kern = build_kernels(S, R, ctx.apps.V, E, str(state.dtype))
         free, head, alive, cap = ctx.mirror(state).arrays()

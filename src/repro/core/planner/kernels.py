@@ -28,7 +28,7 @@ round-up thresholds proven equal to numpy's f64 `free >= d - eps`
 losslessly, in-place f32 updates replay numpy's
 compute-in-f64-then-cast semantics via an explicit astype round-trip,
 and every argmax keeps numpy's first-maximum rule. All public entry points run under
-`jax.experimental.enable_x64` so f64 stays f64 without flipping the
+`jax.enable_x64(True)` so f64 stays f64 without flipping the
 global x64 flag for the rest of the process.
 
 Chunking: callers drive whole rounds through fixed chunk shapes
@@ -56,15 +56,23 @@ def have_jax() -> bool:
         return False
 
 
-def resolve_backend(backend: str) -> str:
-    """Validate a planner backend name at construction time, so a bad
-    config fails loudly instead of at the first failover round."""
+def resolve_backend(backend: str, dtype: str | None = None) -> str:
+    """Validate a planner backend name (and, where given, the planner
+    state dtype) at construction time, so a bad config fails loudly
+    instead of at the first failover round."""
     if backend not in ("numpy", "jax"):
         raise ValueError(f"unknown planner backend {backend!r}; "
                          "expected 'numpy' or 'jax'")
     if backend == "jax" and not have_jax():
         raise RuntimeError("planner backend 'jax' requires jax, which is "
                            "not importable here; use backend='numpy'")
+    if backend == "jax" and dtype == "float64":
+        import jax
+        if jax.default_backend() == "tpu":
+            raise ValueError(
+                "planner backend 'jax' on a TPU runs the Pallas masked "
+                "argmax, and TPU kernels have no 64-bit types: use "
+                "planner_dtype='float32' or backend='numpy'")
     return backend
 
 
@@ -91,11 +99,10 @@ def build_kernels(S: int, R: int, V: int, E: int, dtype_str: str):
     dtype_str: the PlannerState dtype ("float64" | "float32")."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.kernels.planner_argmax.ops import masked_argmax
 
-    with enable_x64():
+    with jax.enable_x64(True):
         f64 = jnp.float64
         st_dtype = jnp.dtype(dtype_str)
 
@@ -219,9 +226,8 @@ def build_scatter():
     writes in place — O(dirty) work, no (S, R) re-materialization.
     Row indices >= S (the bucket padding) drop out."""
     import jax
-    from jax.experimental import enable_x64
 
-    with enable_x64():
+    with jax.enable_x64(True):
         @partial(jax.jit, donate_argnums=(0, 1, 2))
         def scatter_rows(free, head, alive, idx, frows, hrows, arows):
             free = free.at[idx].set(frows, mode="drop")

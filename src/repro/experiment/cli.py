@@ -199,6 +199,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     from repro.experiment.backends import run_experiment
 
     spec = _spec_from_args(args)
+    if spec.backend == "testbed":          # real engines: compiles to keep
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
     res = run_experiment(spec)
     _print_result(res, args.as_json)
     if args.out:

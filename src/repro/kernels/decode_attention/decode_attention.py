@@ -4,7 +4,10 @@ One query token per sequence attends to a long KV cache.  The GPU
 flash-decoding kernel splits KV across SMs and reduces partials in a
 second kernel; on TPU the KV-chunk axis is the sequential last grid
 dimension and the partial (m, l, acc) reduction lives in VMEM scratch —
-one kernel, no inter-core reduction.  Grid: (B, H, n_kv_chunks).
+one kernel, no inter-core reduction.  Grid: (B, KVH, n_kv_chunks): each
+step scores the G = H // KVH query heads that share one KV head against
+one KV chunk, so the (G, hd) query block spans the array's last two dims
+(the TPU block rule) and every KV tile is read once per group.
 
 Layouts: q (B, H, hd); k/v caches (B, KVH, Smax, hd); lens (B,) valid
 entries.  Ring-buffer (sliding-window) caches pass window=0 and a
@@ -42,18 +45,18 @@ def _dec_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale          # (1, hd)
+        q = q_ref[0, 0].astype(jnp.float32) * scale       # (G, hd)
         k = k_ref[0, 0].astype(jnp.float32)               # (bk, hd)
         v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         k_pos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
+            jnp.int32, s.shape, 1)
         mask = k_pos < n_valid
         if window > 0:
             mask &= k_pos >= n_valid - window
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]
+        m_prev = m_scr[...]                               # (G, 1)
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)
@@ -68,7 +71,7 @@ def _dec_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     @pl.when(ik == nk - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, ...] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[0, 0, ...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def decode_attention_bhd(q, k_cache, v_cache, lens, *, window=0,
@@ -87,25 +90,20 @@ def decode_attention_bhd(q, k_cache, v_cache, lens, *, window=0,
 
     kernel = functools.partial(_dec_kernel, scale=scale, block_k=block_k,
                                nk=nk, window=window)
+    group = pl.BlockSpec((1, 1, G, hd), lambda b, g, ik: (b, g, 0, 0))
+    kv = pl.BlockSpec((1, 1, block_k, hd), lambda b, g, ik: (b, g, ik, 0))
     out = pl.pallas_call(
         kernel,
-        grid=(B, H, nk),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),         # lens
-            pl.BlockSpec((1, 1, hd), lambda b, h, ik: (b, h, 0)),
-            pl.BlockSpec((1, 1, block_k, hd),
-                         lambda b, h, ik: (b, h // G, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, hd),
-                         lambda b, h, ik: (b, h // G, ik, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, hd), lambda b, h, ik: (b, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        grid=(B, KVH, nk),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),    # lens
+                  group, kv, kv],
+        out_specs=group,
+        out_shape=jax.ShapeDtypeStruct((B, KVH, G, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, hd), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(lens.astype(jnp.int32), q.reshape(B, H, 1, hd)[:, :, 0], k_cache,
-      v_cache)
-    return out
+    )(lens.astype(jnp.int32), q.reshape(B, KVH, G, hd), k_cache, v_cache)
+    return out.reshape(B, H, hd)

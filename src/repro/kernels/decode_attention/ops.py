@@ -13,10 +13,8 @@ from repro.kernels.decode_attention.decode_attention import \
 
 @partial(jax.jit, static_argnames=("window", "block_k", "interpret"))
 def decode_attention(q, k_cache, v_cache, lens, *, window=0, block_k=256,
-                     interpret=None):
+                     interpret=False):
     """q: (B,1,H,hd); caches (B,Smax,KVH,hd); lens (B,) -> (B,1,H,hd)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     qt = q[:, 0]                                  # (B,H,hd)
     kt = jnp.swapaxes(k_cache, 1, 2)              # (B,KVH,Smax,hd)
     vt = jnp.swapaxes(v_cache, 1, 2)

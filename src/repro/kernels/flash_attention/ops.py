@@ -1,7 +1,8 @@
 """jit'd public wrapper for the flash attention kernel.
 
 Accepts the model's (B, S, H, hd) layout, handles the transpose to the
-kernel's (B, H, S, hd) layout, and falls back to interpret mode off-TPU.
+kernel's (B, H, S, hd) layout. It compiles for the TPU unless the
+caller asks for interpret mode (the CPU tests do).
 """
 
 from __future__ import annotations
@@ -14,17 +15,11 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention.flash_attention import flash_attention_bhsd
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k",
                                    "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=0, block_q=128,
-                    block_k=128, interpret=None):
+                    block_k=128, interpret=False):
     """q: (B,S,H,hd); k,v: (B,S,KVH,hd) -> (B,S,H,hd)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)

@@ -13,9 +13,7 @@ from repro.kernels.int8_matmul.int8_matmul import (int8_matmul_pallas,
 @partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
                                    "interpret"))
 def int8_matmul(x, w_q, scale, *, block_m=128, block_n=128, block_k=512,
-                interpret=None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+                interpret=False):
     return int8_matmul_pallas(x, w_q, scale, block_m=block_m,
                               block_n=block_n, block_k=block_k,
                               interpret=interpret)

@@ -5,7 +5,7 @@ Two implementations, one contract (first maximum among masked-in rows,
 
   * ``pallas`` — the tiled TPU kernel (planner_argmax.py): used when
     the default JAX backend is a TPU, or forced via ``impl="pallas"``
-    (interpret-mode on CPU — the parity tests run it this way);
+    (with ``interpret=True`` on CPU — the parity tests run it this way);
   * ``jnp``    — the jittable jnp equivalent: the CPU fast path the
     jax planner backend inlines into its fused placement scan.
 
@@ -45,18 +45,17 @@ def masked_argmax_jnp(values, mask):
 
 
 def masked_argmax(values, mask, *, impl: str | None = None,
-                  block: int = 512, interpret: bool | None = None):
+                  block: int = 512, interpret: bool = False):
     """(S,) values + (S,) bool mask -> (idx int32, val).
 
     ``impl=None`` auto-selects: the Pallas kernel on TPU, the jnp path
     everywhere else (the kernel still runs anywhere via
-    ``impl="pallas"`` + interpret mode)."""
+    ``impl="pallas", interpret=True``). The kernel needs 32-bit values:
+    TPU kernels have no 64-bit types."""
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "jnp"
     if impl == "jnp":
         return masked_argmax_jnp(values, mask)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return masked_argmax_pallas(values, mask, block=block,
                                 interpret=interpret)
 

@@ -36,11 +36,14 @@ def _masked_argmax_kernel(v_ref, m_ref, idx_ref, val_ref, *, block, n):
 
     v = v_ref[...]                                     # (1, block)
     m = m_ref[...]
-    vv = jnp.where(m, v, -jnp.inf)
+    # typed constants: under `jax.enable_x64` a bare Python scalar is
+    # 64-bit, and Mosaic's lowering of the f64->f32 / i64->i32 convert
+    # recurses until RecursionError (TPU kernels have no 64-bit types)
+    vv = jnp.where(m, v, jnp.array(-jnp.inf, v.dtype))
     tile_max = vv.max()
     # first in-tile column achieving the max (iota ascending, min wins)
     col = jax.lax.broadcasted_iota(jnp.int32, vv.shape, 1)
-    tile_idx = jnp.where(vv == tile_max, col, n).min() + i * block
+    tile_idx = jnp.where(vv == tile_max, col, jnp.int32(n)).min() + i * block
 
     # ascending-tile combine: strict improvement only, so ties keep the
     # earlier (smaller-index) tile — the first-maximum rule
@@ -66,17 +69,17 @@ def masked_argmax_pallas(values, mask, *, block: int = 512,
     m2 = mask.reshape(1, n + pad)
 
     kernel = functools.partial(_masked_argmax_kernel, block=block, n=n)
+    # every index map returns int32 zeros, never Python ints: the planner
+    # traces this under `jax.enable_x64`, where a bare 0 (or a default
+    # index map) becomes an i64 that Mosaic refuses
+    smem = pl.BlockSpec((1, 1), lambda i: (jnp.int32(0), jnp.int32(0)),
+                        memory_space=pltpu.SMEM)
+    tile = pl.BlockSpec((1, block), lambda i: (jnp.int32(0), i))
     idx, val = pl.pallas_call(
         kernel,
         grid=(nt,),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
+        in_specs=[tile, tile],
+        out_specs=[smem, smem],
         out_shape=[
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
             jax.ShapeDtypeStruct((1, 1), values.dtype),

@@ -10,8 +10,6 @@ from repro.kernels.rglru_scan.rglru_scan import rglru_scan_pallas
 
 
 @partial(jax.jit, static_argnames=("block_s", "block_w", "interpret"))
-def rglru_scan(a, b, h0, *, block_s=128, block_w=256, interpret=None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def rglru_scan(a, b, h0, *, block_s=128, block_w=256, interpret=False):
     return rglru_scan_pallas(a, b, h0, block_s=block_s, block_w=block_w,
                              interpret=interpret)

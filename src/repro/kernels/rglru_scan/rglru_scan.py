@@ -6,7 +6,9 @@ adaptation: grid = (B blocks, W blocks, S blocks) with the sequence axis
 last (sequential); each grid step loads a (block_s, block_w) tile of
 (a, b) into VMEM, runs the short sequential scan over block_s with the
 8x128-lane VPU vectorizing the width dim, and carries h across grid
-steps in VMEM scratch.  Wall-clock depth is S/block_s instead of S.
+steps in VMEM scratch. h0 enters as (B, 1, W) so its (1, block_w) block
+meets the TPU rule that a block's last two dims tile by (8, 128) or
+span the array.  Wall-clock depth is S/block_s instead of S.
 
 Inputs are the precomputed gate products: a = exp(log_a), b (both fp32,
 shape (B, S, W)); initial state h0 (B, W).  Returns (h (B,S,W), h_last).
@@ -27,22 +29,16 @@ def _rglru_kernel(a_ref, b_ref, h0_ref, h_ref, hs_scr, *, block_s, ns):
 
     @pl.when(isq == 0)
     def _init():
-        hs_scr[...] = h0_ref[...].astype(jnp.float32)
+        hs_scr[...] = h0_ref[0].astype(jnp.float32)
 
-    a = a_ref[0]                  # (block_s, block_w) fp32
-    b = b_ref[0]
-    h = hs_scr[...]               # (1, block_w)
-
-    def step(t, carry):
-        h = carry
-        at = jax.lax.dynamic_slice_in_dim(a, t, 1, axis=0)
-        bt = jax.lax.dynamic_slice_in_dim(b, t, 1, axis=0)
-        h = at * h + bt
+    def step(t, h):               # h: (1, block_w) fp32
+        # one-row loads/stores straight from the refs: Mosaic lowers a
+        # dynamic ref index, not a dynamic_slice of a loaded value
+        h = a_ref[0, pl.ds(t, 1), :] * h + b_ref[0, pl.ds(t, 1), :]
         h_ref[0, pl.ds(t, 1), :] = h
         return h
 
-    h = jax.lax.fori_loop(0, block_s, step, h)
-    hs_scr[...] = h
+    hs_scr[...] = jax.lax.fori_loop(0, block_s, step, hs_scr[...])
 
 
 def rglru_scan_pallas(a, b, h0, *, block_s=128, block_w=256,
@@ -68,13 +64,13 @@ def rglru_scan_pallas(a, b, h0, *, block_s=128, block_w=256,
                          lambda bb, iw, isq: (bb, isq, iw)),
             pl.BlockSpec((1, block_s, block_w),
                          lambda bb, iw, isq: (bb, isq, iw)),
-            pl.BlockSpec((1, block_w), lambda bb, iw, isq: (bb, iw)),
+            pl.BlockSpec((1, 1, block_w), lambda bb, iw, isq: (bb, 0, iw)),
         ],
         out_specs=pl.BlockSpec((1, block_s, block_w),
                                lambda bb, iw, isq: (bb, isq, iw)),
         out_shape=jax.ShapeDtypeStruct((B, S + pad_s, W), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
         interpret=interpret,
-    )(a, b, h0)
+    )(a, b, h0.reshape(B, 1, W))
     h = h[:, :S]
     return h, h[:, -1]

@@ -10,7 +10,5 @@ from repro.kernels.rwkv6_scan.rwkv6_scan import wkv6_pallas
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6(r, k, v, lw, u, *, chunk=32, interpret=None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def wkv6(r, k, v, lw, u, *, chunk=32, interpret=False):
     return wkv6_pallas(r, k, v, lw, u, chunk=chunk, interpret=interpret)

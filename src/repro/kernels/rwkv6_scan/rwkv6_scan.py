@@ -11,6 +11,9 @@ decay exponents kept <= 0 for fp32 stability.
 
 Inputs per head: r,k,v (B,NH,S,hs) fp32; lw (B,NH,S,hs) log-decay <= 0;
 u (NH,hs) bonus.  Returns (y (B,NH,S,hs), S_out (B,NH,hs,hs)).
+u is passed to the kernel as (NH, 1, hs): a (1, hs) block of a 2-D
+(NH, hs) array breaks the TPU rule that a block's last two dims tile by
+(8, 128) or span the array.
 """
 
 from __future__ import annotations
@@ -21,6 +24,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+# every in-kernel matmul asks for full fp32 precision, as the reference
+# computes
+_HI = jax.lax.Precision.HIGHEST
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, sout_ref,
@@ -38,22 +45,30 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, sout_ref,
     u = u_ref[0]                          # (1, hs)
     s = s_scr[...]                        # (hs, hs)
 
-    cum = jnp.cumsum(lw, axis=0)          # inclusive
+    # inclusive cumsum over time as a lower-triangular ones matmul:
+    # Mosaic has no cumsum lowering
+    ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tril = (ti >= si).astype(jnp.float32)
+    cum = jax.lax.dot_general(tril, lw, (((1,), (0,)), ((), ())),
+                              precision=_HI,
+                              preferred_element_type=jnp.float32)
     cum_prev = cum - lw                   # exclusive
     cum_last = cum[-1:]                   # (1, hs)
 
     # inter-chunk: y += (r * e^{cum_prev}) @ S_in
     r_dec = r * jnp.exp(cum_prev)
     y = jax.lax.dot_general(r_dec, s, (((1,), (0,)), ((), ())),
+                            precision=_HI,
                             preferred_element_type=jnp.float32)
     # intra-chunk strict-lower part: A[t,s] = sum_k r_t k_s e^{cum_prev_t - cum_s}
     k_div = k * jnp.exp(-cum)             # NOTE: may be large; masked below
     a = jax.lax.dot_general(r_dec, k_div, (((1,), (1,)), ((), ())),
+                            precision=_HI,
                             preferred_element_type=jnp.float32)
-    ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     a = jnp.where(ti > si, a, 0.0)
     y = y + jax.lax.dot_general(a, v, (((1,), (0,)), ((), ())),
+                                precision=_HI,
                                 preferred_element_type=jnp.float32)
     # diagonal bonus: y_t += (r_t . u*k_t) v_t
     diag = jnp.sum(r * (u * k), axis=-1, keepdims=True)
@@ -63,7 +78,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, sout_ref,
     # state update: S_out = e^{cum_last} ⊙ S_in + sum_s (k_s e^{cum_last-cum_s})^T v_s
     k_dec = k * jnp.exp(cum_last - cum)
     s_new = jnp.exp(cum_last).reshape(-1, 1) * s + jax.lax.dot_general(
-        k_dec, v, (((0,), (0,)), ((), ())),
+        k_dec, v, (((0,), (0,)), ((), ())), precision=_HI,
         preferred_element_type=jnp.float32)
     s_scr[...] = s_new
 
@@ -99,7 +114,7 @@ def wkv6_pallas(r, k, v, lw, u, *, chunk=32, interpret=False):
             pl.BlockSpec((1, 1, chunk, hs), lambda b, h, ic: (b, h, ic, 0)),
             pl.BlockSpec((1, 1, chunk, hs), lambda b, h, ic: (b, h, ic, 0)),
             pl.BlockSpec((1, 1, chunk, hs), lambda b, h, ic: (b, h, ic, 0)),
-            pl.BlockSpec((1, hs), lambda b, h, ic: (h, 0)),
+            pl.BlockSpec((1, 1, hs), lambda b, h, ic: (h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, chunk, hs), lambda b, h, ic: (b, h, ic, 0)),
@@ -111,5 +126,5 @@ def wkv6_pallas(r, k, v, lw, u, *, chunk=32, interpret=False):
         ],
         scratch_shapes=[pltpu.VMEM((hs, hs), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, lw, u)
+    )(r, k, v, lw, u.reshape(NH, 1, hs))
     return y[:, :, :S], s_out

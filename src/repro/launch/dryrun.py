@@ -131,7 +131,7 @@ def _lower_one(cfg, shape, mesh, opt, microbatches: int = 1,
                          out_shardings=(logits_sh, cache_sh))
         args = (param_shapes, cache_shapes, batch_shapes)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*args)
         compiled = lowered.compile()
     return lowered, compiled

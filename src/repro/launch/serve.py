@@ -30,7 +30,9 @@ def main():
     ap.add_argument("--seed", type=int, default=1)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serving.testbed import MiniTestbed
+    enable_compile_cache()
     archs = [a.strip() for a in args.archs.split(",") if a.strip()]
     print(f"deploying {len(archs)} applications under policy="
           f"{args.policy} on {args.sites}x{args.servers_per_site} cells "

@@ -27,61 +27,11 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def _mesh_nonempty(m) -> bool:
-    empty = getattr(m, "empty", None)
-    if empty is not None:
-        return not empty
-    return bool(getattr(m, "axis_names", ()))
-
-
-def _abstract_mesh_getters():
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        yield get                  # public export (newer jax)
-    try:
-        from jax._src import mesh as _mesh_lib
-        yield _mesh_lib.get_abstract_mesh
-    except Exception:              # pragma: no cover - very old jax
-        return
-
-
-def _mesh_from_abstract() -> Optional[Mesh]:
-    """Ambient mesh via the current abstract-mesh API (set by
-    `use_mesh`/`set_mesh`): `jax.sharding.get_abstract_mesh` where it
-    exists, else the same accessor from `jax._src.mesh` on jax
-    versions that predate the public export."""
-    for get in _abstract_mesh_getters():
-        try:
-            am = get()
-        except Exception:
-            continue
-        if am is not None and _mesh_nonempty(am):
-            return am
-    return None
-
-
-def _mesh_from_pxla() -> Optional[Mesh]:
-    """Legacy `with Mesh(...):` scope via the deprecated
-    `pxla.thread_resources` — kept as a fallback for callers still on
-    the context-manager idiom."""
-    import warnings
-    try:
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            from jax.interpreters import pxla
-            mesh = pxla.thread_resources.env.physical_mesh
-    except Exception:
-        return None
-    if mesh is not None and not mesh.empty:
-        return mesh
-    return None
-
-
 def current_mesh() -> Optional[Mesh]:
-    """Ambient mesh from `use_mesh`/`set_mesh` or a `with mesh:` scope,
-    or None. The non-deprecated abstract-mesh discovery runs first; the
-    pxla thread-resources probe is only a legacy fallback."""
-    return _mesh_from_abstract() or _mesh_from_pxla()
+    """The ambient mesh set by `jax.set_mesh`, or None. A bare
+    `with mesh:` scope does not set it and is not seen here."""
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.empty else am
 
 
 def _axis_size(mesh, name) -> int:
@@ -123,12 +73,8 @@ def logical_constraint(x, spec: P):
     mesh = current_mesh()
     if mesh is None:
         return x
-    fs = filter_spec(spec, mesh, x.shape)
-    try:
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, fs))
-    except (ValueError, TypeError):
-        # abstract mesh path (inside jit under `use_mesh`)
-        return jax.lax.with_sharding_constraint(x, fs)
+    return jax.lax.with_sharding_constraint(
+        x, filter_spec(spec, mesh, x.shape))
 
 
 # ---------------------------------------------------------------------------

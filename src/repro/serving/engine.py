@@ -39,15 +39,21 @@ class Request:
 
 
 class InferenceEngine:
-    """Slot-based continuous batching for one model instance."""
+    """Slot-based continuous batching for one model instance.
+
+    With a `device`, params and cache are committed to it, so every
+    step runs there whichever thread calls it."""
 
     def __init__(self, cfg: ModelConfig, params, *, batch_slots: int = 4,
-                 max_len: int = 256):
+                 max_len: int = 256, device=None):
         self.cfg = cfg
-        self.params = params
         self.batch_slots = batch_slots
         self.max_len = max_len
-        self.cache = MDL.init_cache(cfg, batch_slots, max_len)
+        cache = MDL.init_cache(cfg, batch_slots, max_len)
+        if device is not None:
+            params, cache = jax.device_put((params, cache), device)
+        self.params = params
+        self.cache = cache
         self.slots: List[Optional[Request]] = [None] * batch_slots
         self.remaining: np.ndarray = np.zeros(batch_slots, np.int32)
         self._lock = threading.Lock()
@@ -114,6 +120,11 @@ class InferenceEngine:
                     finished.append(req)
                     self.slots[i] = None
         return finished
+
+    def device_bytes(self) -> int:
+        """Bytes this engine holds on its device: params plus cache."""
+        return sum(x.nbytes for x in
+                   jax.tree_util.tree_leaves((self.params, self.cache)))
 
     def active_count(self) -> int:
         with self._lock:

@@ -3,7 +3,8 @@
 Real work happens here in the mini-testbed: `load()` actually builds JAX
 params and compiles the engine (that wall-clock time IS the measured
 cold-load cost, the analogue of the paper's Fig. 2b Triton loads), and
-`submit()` runs real batched inference on the CPU device.
+`submit()` runs real batched inference on the worker's device: the
+chip it is bound to, or JAX's default device (the CPU in tests).
 """
 
 from __future__ import annotations
@@ -11,7 +12,8 @@ from __future__ import annotations
 import queue
 import threading
 import time
-from typing import Dict
+import zlib
+from typing import Callable, Dict
 
 import jax
 
@@ -21,17 +23,41 @@ from repro.models import model as MDL
 from repro.serving.engine import InferenceEngine, Request
 
 
+def checkpoint_params(variant: Variant):
+    """The variant's deterministic 'checkpoint': random weights seeded
+    by a CRC of its name, so every process and every rerun builds the
+    same weights (Python's `hash` of a str is salted per process)."""
+    cfg = variant.config
+    assert cfg is not None, "testbed variants need real configs"
+    return MDL.init_params(
+        jax.random.PRNGKey(zlib.crc32(variant.name.encode())), cfg)
+
+
 class WorkerServer:
-    """Thread-backed serving cell with heartbeat + engine hosting."""
+    """Thread-backed serving cell with heartbeat + engine hosting.
+
+    `device` binds the cell to one chip: its params and engine caches
+    live there, and a crash frees that chip. None = JAX's default
+    device."""
 
     def __init__(self, server_id: str, detector: FailureDetector, *,
+                 on_error: Callable[[BaseException], None],
                  heartbeat_s: float = 0.020, batch_slots: int = 2,
-                 max_len: int = 96):
+                 max_len: int = 96, device=None):
         self.id = server_id
         self.detector = detector
         self.heartbeat_s = heartbeat_s
         self.batch_slots = batch_slots
         self.max_len = max_len
+        self.device = device
+        # a decode step that raises on a live cell (an HBM OOM, an XLA
+        # error) is a fault of the program, handed here
+        self.on_error = on_error
+        # variant -> wall of its last load. load() returns it, but only
+        # the executor's failover loads keep it (in their LoadTicket):
+        # deploy's primary load and the warm-backup load drop it, and
+        # this is the one place all three paths pass
+        self.load_s: Dict[str, float] = {}
         self.engines: Dict[str, InferenceEngine] = {}     # variant -> engine
         self.cold_store: Dict[str, Variant] = {}          # on "disk"
         self.shard_store: Dict[str, object] = {}          # TP slices (HBM)
@@ -90,7 +116,12 @@ class WorkerServer:
                 continue
             if not self._alive.is_set():
                 return
-            fn()
+            try:
+                fn()
+            except Exception as e:      # noqa: BLE001
+                if not self._alive.is_set():
+                    return              # killed mid-step
+                self.on_error(e)
 
     # -- model management (Triton Load/Unload analogue) -----------------------
     def stage_cold(self, app: Application, variant: Variant):
@@ -103,18 +134,18 @@ class WorkerServer:
         if not self.alive:
             raise RuntimeError(f"{self.id} is down")
         t0 = time.monotonic()
-        cfg = variant.config
-        assert cfg is not None, "testbed variants need real configs"
-        params = MDL.init_params(jax.random.PRNGKey(hash(variant.name)
-                                                    % (2**31)), cfg)
-        eng = InferenceEngine(cfg, params, batch_slots=self.batch_slots,
-                              max_len=self.max_len)
-        eng.warmup()
+        with jax.default_device(self.device):
+            eng = InferenceEngine(variant.config, checkpoint_params(variant),
+                                  batch_slots=self.batch_slots,
+                                  max_len=self.max_len, device=self.device)
+            eng.warmup()
+        wall = time.monotonic() - t0
         with self._lock:
             if not self.alive:
                 raise RuntimeError(f"{self.id} died during load")
             self.engines[variant.name] = eng
-        return time.monotonic() - t0
+            self.load_s[variant.name] = wall
+        return wall
 
     def install(self, variant_name: str, engine: InferenceEngine):
         """Adopt a pre-built engine (tensor-parallel deployments gather

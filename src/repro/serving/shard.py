@@ -31,14 +31,13 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
-import jax
 import numpy as np
 
 from repro.core.shardgroup import ShardGroup, ShardGroupManager, slice_name
 from repro.core.variants import Application, Variant
-from repro.models import model as MDL
 from repro.parallel.sharding import param_specs
 from repro.serving.engine import InferenceEngine
+from repro.serving.server import checkpoint_params
 
 # ---------------------------------------------------------------------------
 # param-tree partitioning along the production TP ("model") axes
@@ -125,16 +124,6 @@ def gather(rank_trees: List, axes):
     return walk(t0, axes, rank_trees)
 
 
-def checkpoint_params(variant: Variant):
-    """The deterministic 'checkpoint': identical to what
-    `WorkerServer.load` builds for this variant, so a re-materialized
-    slice is bit-identical to the lost one."""
-    cfg = variant.config
-    assert cfg is not None, "sharded testbed variants need real configs"
-    return MDL.init_params(
-        jax.random.PRNGKey(hash(variant.name) % (2**31)), cfg)
-
-
 @dataclass
 class _GroupLayout:
     """Per-group partition metadata kept OFF the workers (the slices
@@ -208,7 +197,7 @@ class TestbedShardManager(ShardGroupManager):
         w = self.tb.workers[server_id]
         eng = InferenceEngine(g.base.config, params,
                               batch_slots=w.batch_slots,
-                              max_len=w.max_len)
+                              max_len=w.max_len, device=w.device)
         eng.warmup()
         w.install(name, eng)
 

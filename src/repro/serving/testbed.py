@@ -1,4 +1,4 @@
-"""Thread-based mini-testbed: the paper's edge testbed, on one CPU.
+"""Thread-based mini-testbed: the paper's edge testbed, in one process.
 
 Real components everywhere the paper's testbed had them:
   * WorkerServer threads host real JAX engines and send real heartbeats
@@ -21,11 +21,21 @@ outcomes measured by the client threads are folded through the same
 client-observed MTTR/availability/goodput mean the same thing on both
 backends.
 
-Model ladders use the reduced smoke configs so everything runs on CPU;
-capacities come from the shared arch-mix sizing rule
-(`repro.experiment.workload`), which is what lets the simulator run the
-exact same workload on the exact same cluster shape for cross-backend
-parity experiments.
+Worker i serves on `jax.devices()[i % n]`: on a TPU host each worker
+owns a chip (more workers than chips share them round-robin), on the
+CPU they share the one device. The arch-mix workload uses the reduced
+smoke ladders (`repro.experiment.workload`); `apps=` serves any ladder,
+e.g. a model at its published widths (`chip_smoke.py`). Capacities come
+from the shared arch-mix sizing rule, which is what lets the simulator
+run the exact same workload on the exact same cluster shape for
+cross-backend parity experiments.
+
+Failures are not swallowed: an exception in a load, a warm-backup load,
+a client request or a worker's decode loop is recorded in
+`MiniTestbed.errors`, and `shutdown()` re-raises it. Only a
+`RuntimeError` from a worker that is down counts as "the server died";
+JAX's own errors (an HBM OOM, a failed compile) are RuntimeErrors too,
+and on a live worker they are faults.
 """
 
 from __future__ import annotations
@@ -35,8 +45,9 @@ import random
 import threading
 import time
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence
 
+import jax
 import numpy as np
 
 from repro.core.cluster import Cluster, Server
@@ -63,6 +74,8 @@ from repro.serving.workload import make_request
 
 DETECT_POLL_S = 0.02          # sweeper poll (controller sweep, §5.1)
 REPROTECT_EVERY_S = 1.0       # continuous re-protection loop period
+WARM_DEADLINE_S = 120.0       # deploy raises if a warm backup is not
+                              # resident by then
 
 
 class TestbedExecutor(LoadExecutor):
@@ -78,10 +91,16 @@ class TestbedExecutor(LoadExecutor):
 
     def __init__(self, workers: Dict[str, WorkerServer], router: Router,
                  ctl_lock: threading.RLock,
-                 registry: Optional[ModelRegistry] = None):
+                 registry: Optional[ModelRegistry] = None, *,
+                 on_error: Callable[[BaseException], None]):
         self.workers = workers
         self.router = router
         self.ctl_lock = ctl_lock
+        # a load that fails for any reason but a dead server is a fault
+        # of the program (HBM OOM, a compile error): handed to the
+        # testbed, which re-raises it at shutdown. JAX raises those as
+        # RuntimeError too, so only a dead worker excuses one.
+        self.on_error = on_error
         # model-state plane: fetch-path selection + load-cost
         # calibration. Every REAL load's wall time is observed into the
         # registry's LoadCostModel (the Fig. 2b feedback loop), and
@@ -116,6 +135,13 @@ class TestbedExecutor(LoadExecutor):
     def idle(self) -> bool:
         with self._n_lock:
             return self._outstanding == 0
+
+    def _fault(self, exc: BaseException, server_id: str) -> None:
+        """A load on `server_id` raised: a RuntimeError from a worker
+        that is down is its death; anything else is a fault."""
+        if not (isinstance(exc, RuntimeError)
+                and not self.workers[server_id].alive):
+            self.on_error(exc)
 
     def degrade_link(self, link: str, factor: float, duration: float):
         """LinkDegrade analogue: scale the emulated fetch sleeps that
@@ -165,11 +191,8 @@ class TestbedExecutor(LoadExecutor):
                         self.registry.calibration.observe(
                             variant, source, sleep_s + wall)
                         self.registry.stage(variant.name, server_id)
-            except RuntimeError:
-                return                    # server died mid-load
-            except Exception:             # noqa: BLE001
-                import traceback
-                traceback.print_exc()
+            except Exception as e:        # noqa: BLE001
+                self._fault(e, server_id)
                 return
             with self.ctl_lock:
                 on_ready(time.monotonic())
@@ -191,11 +214,8 @@ class TestbedExecutor(LoadExecutor):
                         self.workers[server_id].load(app, variant)
                 if self.registry is not None:
                     self.registry.stage(variant.name, server_id)
-            except RuntimeError:
-                pass
-            except Exception:             # noqa: BLE001
-                import traceback
-                traceback.print_exc()
+            except Exception as e:        # noqa: BLE001
+                self._fault(e, server_id)
         self._spawn(work)
 
     def replicate(self, app, variant, server_id, on_done=None):
@@ -286,6 +306,13 @@ class TestbedTelemetry:
         None = the plain served/failed path."""
         with self._lock:
             self._attempts[app_id].append((t, ok, accuracy, req, outcome))
+
+    def served(self, app_id: str) -> List[tuple]:
+        """(accuracy, request) of every request the app's clients had
+        admitted, in arrival order."""
+        with self._lock:
+            return [(r[2], r[3]) for r in self._attempts.get(app_id, ())
+                    if r[1] and r[3] is not None]
 
     # -- aggregation --------------------------------------------------------
     def summarize(self, t_end: float) -> TrafficSummary:
@@ -397,6 +424,8 @@ class MiniTestbed:
         self.detector = FailureDetector(self.clock, interval=0.020)
         self.router = Router()
         self.telemetry = TestbedTelemetry()
+        self.errors: List[BaseException] = []   # re-raised by shutdown()
+        self._err_lock = threading.Lock()
         self._ctl_lock = threading.RLock()
         self._archs = list(archs or TESTBED_ARCHS)
 
@@ -428,13 +457,17 @@ class MiniTestbed:
             cloud_bw=cloud_bw, replication=replication))
         self.registry = ModelRegistry(self.cluster, self.cluster.storage)
 
-        # --- worker threads ----------------------------------------------
+        # --- worker threads, one device each ------------------------------
+        devices = jax.devices()
         self.workers: Dict[str, WorkerServer] = {
-            s.id: WorkerServer(s.id, self.detector).start()
-            for s in servers}
+            s.id: WorkerServer(s.id, self.detector,
+                               device=devices[i % len(devices)],
+                               on_error=self._record_error).start()
+            for i, s in enumerate(servers)}
         self.executor = TestbedExecutor(self.workers, self.router,
                                         self._ctl_lock,
-                                        registry=self.registry)
+                                        registry=self.registry,
+                                        on_error=self._record_error)
         self.controller = FailLiteController(
             self.cluster, self.clock, self.executor, policy=policy,
             alpha=alpha, site_independence=site_independence,
@@ -465,6 +498,16 @@ class MiniTestbed:
             self.shards = TestbedShardManager(
                 self, tp_degree=tp_degree, policy=shard_policy)
             self.executor.shard_plane = self.shards
+
+    def _record_error(self, exc: BaseException):
+        with self._err_lock:
+            self.errors.append(exc)
+
+    def raise_errors(self):
+        """Re-raise the first recorded fault, if any."""
+        with self._err_lock:
+            if self.errors:
+                raise self.errors[0]
 
     # -- routing observers (replace the old monkey-patch) -------------------
     def _on_route_set(self, app_id: str, server_id: str,
@@ -659,10 +702,14 @@ class MiniTestbed:
             warm = self.controller.plan_warm_backups()
         # prepare_warm loads run in the background; wait for residency so
         # the experiment starts from the paper's protected steady state
-        deadline = time.monotonic() + 120.0
+        deadline = time.monotonic() + WARM_DEADLINE_S
         for app_id, (variant, sid) in warm.items():
-            while (not self.workers[sid].has(variant.name)
-                   and time.monotonic() < deadline):
+            while not self.workers[sid].has(variant.name):
+                self.raise_errors()
+                if time.monotonic() > deadline:
+                    raise TimeoutError(
+                        f"warm backup {variant.name} of {app_id} not "
+                        f"resident on {sid} after {WARM_DEADLINE_S:.0f} s")
                 time.sleep(0.05)
         self._sync_backups()
         return self
@@ -677,6 +724,7 @@ class MiniTestbed:
             acc = math.nan
             req = None
             outcome = None
+            w = None
             seq += 1
             try:
                 if self.resilience is not None:
@@ -697,8 +745,13 @@ class MiniTestbed:
                             if ok:
                                 acc = self._accuracy_of(app, vname)
                                 st_ok += 1
-            except Exception:                      # noqa: BLE001
+            except Exception as e:                 # noqa: BLE001
                 ok = False
+                # a RuntimeError from a server that is down is its
+                # death; anything else (JAX's errors included) a fault
+                if not (isinstance(e, RuntimeError)
+                        and w is not None and not w.alive):
+                    self._record_error(e)
             self.telemetry.record(app.id, time.monotonic(), ok, acc,
                                   req if ok else None, outcome=outcome)
             time.sleep(1.0 / (hz * self._spike_factor.get(app.id, 1.0)))
@@ -984,7 +1037,8 @@ class MiniTestbed:
     def shutdown(self):
         """Stop every thread this testbed started and JOIN it, so no
         JAX work survives into interpreter teardown (the old abort-at-
-        exit came from daemon threads compiling during shutdown)."""
+        exit came from daemon threads compiling during shutdown). Then
+        re-raise the first error a load or a client recorded."""
         self._stop.set()
         for timer in self._timers:
             timer.cancel()
@@ -995,3 +1049,4 @@ class MiniTestbed:
             w.kill()
         for w in self.workers.values():
             w.join(timeout=2.0)
+        self.raise_errors()

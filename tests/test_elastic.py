@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from repro import configs
+from repro.launch.mesh import make_mesh
 from repro.models import model as MDL
 from repro.parallel import sharding as SH
 from repro.training import checkpoint as CKPT
@@ -30,7 +31,7 @@ opt_state = opt.init(params)
 CKPT.save_checkpoint(r"{tmp_path}", 7, params, opt_state)
 
 # restore onto a 2x4 mesh (as if 8 of 16 hosts survived a pod loss)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 tmpl_p = MDL.param_shapes(cfg)
 tmpl_o = opt.state_shapes(tmpl_p)
 shard_p = SH.param_shardings(tmpl_p, mesh)

@@ -511,6 +511,19 @@ def test_planner_backend_registry_and_validation():
             get_planner("greedy", backend="jax")
 
 
+def test_jax_backend_refuses_float64_on_tpu(monkeypatch):
+    """On a TPU the jax backend runs the Pallas argmax, which has no
+    64-bit types: float64 state fails at construction, float32 passes."""
+    jax = pytest.importorskip("jax")
+    from repro.core.planner import resolve_backend
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="no 64-bit types"):
+        resolve_backend("jax", "float64")
+    assert resolve_backend("jax", "float32") == "jax"
+    assert resolve_backend("numpy", "float64") == "numpy"
+
+
 @pytest.mark.slow
 def test_simulation_jax_backend_matches_numpy():
     """End-to-end: the same failure scenario under planner_backend

@@ -20,10 +20,11 @@ def test_drop_axes_variants():
 
 def test_serving_param_shardings_drop_fsdp():
     from repro import configs
+    from repro.launch.mesh import make_mesh
     from repro.models import model as MDL
     from repro.parallel import sharding as SH
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = configs.get_smoke("qwen2.5-3b")
     shapes = MDL.param_shapes(cfg)
     sh_serve = SH.param_shardings(shapes, mesh, serving=True)

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.parallel import sharding as SH
 
 # The filter_spec divisibility property test lives in
@@ -24,39 +25,26 @@ class FakeMesh:
 
 
 def test_current_mesh_abstract_path():
-    """The non-deprecated abstract-mesh discovery is probed FIRST and
-    wins without touching the legacy pxla fallback."""
+    """The ambient mesh is the one `jax.set_mesh` installs, read back
+    through `jax.sharding.get_abstract_mesh`."""
     assert SH.current_mesh() is None
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    if hasattr(jax.sharding, "use_mesh"):          # newer jax
-        ctx = jax.sharding.use_mesh(mesh)
-    else:                                          # pre-public-export jax
-        from jax._src import mesh as mesh_lib
-        ctx = mesh_lib.set_abstract_mesh(mesh.abstract_mesh)
-    with ctx:
-        am = SH._mesh_from_abstract()
-        assert am is not None
-        assert tuple(am.axis_names) == ("data", "model")
-        # the pxla probe sees nothing here: only the abstract path hits
+    mesh = make_mesh((1, 1), ("data", "model"))
+    with jax.set_mesh(mesh):
         got = SH.current_mesh()
         assert got is not None
         assert tuple(got.axis_names) == ("data", "model")
-    assert SH._mesh_from_abstract() is None
     assert SH.current_mesh() is None
 
 
 def test_current_mesh_pxla_fallback_path():
-    """The legacy `with Mesh(...):` context still resolves, through the
-    fallback probe."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    """A bare `with mesh:` scope installs no ambient mesh: callers use
+    `jax.set_mesh`, and the model's sharding constraints stay no-ops
+    outside it."""
+    mesh = make_mesh((1, 1), ("data", "model"))
     with mesh:
-        pm = SH._mesh_from_pxla()
-        assert pm is not None and not pm.empty
-        assert tuple(pm.axis_names) == ("data", "model")
-        got = SH.current_mesh()
-        assert got is not None
-        assert tuple(got.axis_names) == ("data", "model")
-    assert SH._mesh_from_pxla() is None
+        assert SH.current_mesh() is None
+        x = jax.numpy.ones((2, 4))
+        assert SH.logical_constraint(x, P("data", None)) is x
     assert SH.current_mesh() is None
 
 
@@ -80,7 +68,7 @@ def test_param_specs_cover_all_archs():
 def test_decode_cache_shardings_long_context():
     """Batch-1 long-context caches shard the sequence dim instead."""
     from repro.parallel.sharding import decode_cache_shardings
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cache_shapes = {
         "pos": jax.ShapeDtypeStruct((1,), jnp.int32),
         "cycles": [{"k": jax.ShapeDtypeStruct((4, 1, 1024, 2, 64),
@@ -103,18 +91,17 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 from repro import configs
 from repro.launch.dryrun import _lower_one
+from repro.launch.mesh import make_mesh
 from repro.configs.shapes import ShapeCell
 from repro.training.optimizer import AdamW
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = configs.get_smoke("qwen2.5-3b").replace(
     param_dtype="bfloat16", remat=True)
 shape = ShapeCell("t", "train", 64, 8)
 lowered, compiled = _lower_one(cfg, shape, mesh, AdamW())
 assert compiled.memory_analysis().temp_size_in_bytes >= 0
 cost = compiled.cost_analysis()
-if isinstance(cost, list):      # older jaxlib: one dict per computation
-    cost = cost[0] if cost else {}
 assert cost.get("flops", 0) > 0
 print("SMALL-MESH-DRYRUN-OK")
 """
