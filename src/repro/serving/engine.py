@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.models import model as MDL
 from repro.models.config import ModelConfig
+from repro.serving import spans
 
 
 @dataclass
@@ -42,11 +43,14 @@ class InferenceEngine:
     """Slot-based continuous batching for one model instance.
 
     With a `device`, params and cache are committed to it, so every
-    step runs there whichever thread calls it."""
+    step runs there whichever thread calls it. `tags` (the serving cell
+    and the rung) go on every admission's span (`repro.serving.spans`)."""
 
     def __init__(self, cfg: ModelConfig, params, *, batch_slots: int = 4,
-                 max_len: int = 256, device=None):
+                 max_len: int = 256, device=None,
+                 tags: Optional[dict] = None):
         self.cfg = cfg
+        self.tags = dict(tags or {})
         self.batch_slots = batch_slots
         self.max_len = max_len
         cache = MDL.init_cache(cfg, batch_slots, max_len)
@@ -85,16 +89,20 @@ class InferenceEngine:
                 return False
             self.slots[slot] = req
             self.remaining[slot] = req.max_new_tokens
-        # single-sequence prefill into the slot (pos bookkeeping per slot)
-        prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
-        sub = MDL.cache_take_slot(self.cache, slot)
-        sub["pos"] = jnp.zeros((1,), jnp.int32)
-        logits, sub = self._prefill_one(self.params, sub, prompt)
-        with self._lock:
-            self.cache = MDL.cache_put_slot(self.cache, slot, sub)
-            first = int(jnp.argmax(logits[0]))
-            req.tokens.append(first)
-            req.first_token_at = time.monotonic()
+        with spans.span("engine.admit", id=req.id,
+                        prompt_len=len(req.prompt), **self.tags):
+            # single-sequence prefill into the slot (pos bookkeeping per
+            # slot)
+            prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
+            sub = MDL.cache_take_slot(self.cache, slot)
+            sub["pos"] = jnp.zeros((1,), jnp.int32)
+            logits, sub = self._prefill_one(self.params, sub, prompt)
+            with self._lock:
+                self.cache = MDL.cache_put_slot(self.cache, slot, sub)
+                with spans.span("engine.first_token", id=req.id):
+                    first = int(jnp.argmax(logits[0]))
+                req.tokens.append(first)
+                req.first_token_at = time.monotonic()
         return True
 
     # -- decode ---------------------------------------------------------------
@@ -106,9 +114,12 @@ class InferenceEngine:
                 return []
             last = [r.tokens[-1] if r is not None and r.tokens else 0
                     for r in self.slots]
-        tok = jnp.asarray(last, jnp.int32)
-        logits, self.cache = self._decode(self.params, self.cache, tok)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            ids = tuple(self.slots[i].id for i in active)
+        with spans.span("engine.decode", ids=ids):
+            tok = jnp.asarray(last, jnp.int32)
+            logits, self.cache = self._decode(self.params, self.cache, tok)
+        with spans.span("engine.sync", ids=ids):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))
         finished = []
         with self._lock:
             for i in active:

@@ -26,6 +26,8 @@ from __future__ import annotations
 import threading
 from typing import Callable, Dict, List, Optional, Tuple
 
+from repro.serving import spans
+
 
 class Router:
     def __init__(self):
@@ -43,10 +45,11 @@ class Router:
         Returns the epoch assigned to this change (strictly monotonic
         across threads).
         """
-        with self._lock:
+        with spans.span("router.set_route", app=app_id, server=server_id,
+                        variant=variant) as sp, self._lock:
             self._routes[app_id] = (server_id, variant)
             self._epoch += 1
-            epoch = self._epoch
+            epoch = sp.attrs["epoch"] = self._epoch
             for fn in list(self._subscribers):
                 fn(app_id, server_id, variant)       # push notification
             for fn in list(self._versioned):

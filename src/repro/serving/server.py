@@ -137,7 +137,9 @@ class WorkerServer:
         with jax.default_device(self.device):
             eng = InferenceEngine(variant.config, checkpoint_params(variant),
                                   batch_slots=self.batch_slots,
-                                  max_len=self.max_len, device=self.device)
+                                  max_len=self.max_len, device=self.device,
+                                  tags={"server": self.id,
+                                        "rung": variant.name})
             eng.warmup()
         wall = time.monotonic() - t0
         with self._lock:

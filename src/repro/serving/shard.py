@@ -197,7 +197,8 @@ class TestbedShardManager(ShardGroupManager):
         w = self.tb.workers[server_id]
         eng = InferenceEngine(g.base.config, params,
                               batch_slots=w.batch_slots,
-                              max_len=w.max_len, device=w.device)
+                              max_len=w.max_len, device=w.device,
+                              tags={"server": server_id, "rung": name})
         eng.warmup()
         w.install(name, eng)
 

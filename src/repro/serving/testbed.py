@@ -67,6 +67,7 @@ from repro.core.variants import Application
 from repro.experiment.workload import (ARCH_COMPUTE_CAP, TESTBED_ARCHS,
                                        arch_mem_cap, build_arch_apps,
                                        testbed_ladder)
+from repro.serving import spans
 from repro.serving.router import Router
 from repro.serving.server import WorkerServer
 from repro.serving.shard import TestbedShardManager
@@ -781,7 +782,9 @@ class MiniTestbed:
             t_fail = min(self._kill_times.get(sid, now) for sid in newly)
             if self._detect_latency is None:
                 self._detect_latency = now - t_fail
-            with self._ctl_lock:
+            spans.record("testbed.detect", t_fail, now, servers=list(newly))
+            with self._ctl_lock, spans.span("testbed.handle_failures",
+                                            servers=list(newly)):
                 self.controller.handle_failures(newly, t_fail)
             self._sync_backups()
 
@@ -808,6 +811,8 @@ class MiniTestbed:
             if sid in sids:
                 self.telemetry.mark_down(app_id, t_kill, epoch)
                 marked.add(app_id)
+        spans.record("testbed.kill", t_kill, t_kill, servers=list(sids),
+                     apps=sorted(marked))
         if self.shards is not None:
             # shard groups darken when ANY member dies unless the loss
             # degrades seamlessly on a surviving lead — same rule the

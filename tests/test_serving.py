@@ -1,6 +1,7 @@
 """Serving runtime: engine consistency, router semantics, and a compact
 real-failure testbed integration test."""
 
+import time
 
 import jax
 import jax.numpy as jnp
@@ -11,6 +12,7 @@ pytestmark = pytest.mark.slow  # JAX compile-heavy: full CI tier only
 
 from repro import configs
 from repro.models import model as MDL
+from repro.serving import spans
 from repro.serving.engine import InferenceEngine, Request
 from repro.serving.router import Router
 
@@ -77,8 +79,25 @@ def test_mini_testbed_failover_end_to_end():
                      seed=3, headroom=0.35)
     try:
         tb.deploy()
+        t0 = time.monotonic()
         res = tb.run_failure_experiment(observe_s=25.0, client_hz=10.0)
         assert res["detect_latency_s"] < 0.5
+        # the failover's spans, in the order the testbed runs them
+        got = sorted((s for s in spans.snapshot().spans if s.start >= t0),
+                     key=lambda s: s.start)
+        kill, = [s for s in got if s.name == "testbed.kill"]
+        victim = res["victim"]
+        assert kill.attrs["servers"] == [victim] and kill.attrs["apps"]
+        detect = next(s for s in got if s.name == "testbed.detect"
+                      and victim in s.attrs["servers"])
+        handle = next(s for s in got if s.name == "testbed.handle_failures"
+                      and s.start >= detect.end)
+        reroute = next(s for s in got if s.name == "router.set_route"
+                       and s.start > kill.start
+                       and s.attrs["app"] in kill.attrs["apps"]
+                       and s.attrs["server"] != victim)
+        assert detect.start == kill.start
+        assert kill.start < detect.end <= handle.start < reroute.start
         s = res["summary"]
         assert s["n"] >= 1
         assert s["recovery_rate"] == 1.0
