@@ -460,8 +460,16 @@ def forward_features(params, cfg: ModelConfig, tokens, prefix_embeds=None):
     return norm(params["final_norm"], x, cfg.norm_eps), aux
 
 
-def prefill(params, cfg: ModelConfig, tokens, cache, prefix_embeds=None):
-    """Process a prompt, fill the cache. Returns (last-token logits, cache)."""
+def prefill(params, cfg: ModelConfig, tokens, cache, prefix_embeds=None,
+            length=None):
+    """Process a prompt, fill the cache. Returns (last-token logits, cache).
+
+    `length` (decoder-only models; may be traced): the prompt's true
+    length when `tokens` is padded after it. The logits are then those
+    at `length - 1` and `pos` advances by `length`. Causal attention
+    hides the pads from the prompt, and decode masks the cache beyond
+    `pos` and overwrites it, so global attention never reads the pads'
+    K/V; a ring buffer or a recurrent state would take them in."""
     if cfg.is_encoder_decoder:
         from repro.models import encdec
         return encdec.prefill(params, cfg, tokens, cache, prefix_embeds)
@@ -483,8 +491,11 @@ def prefill(params, cfg: ModelConfig, tokens, cache, prefix_embeds=None):
     for p_l, c_l, kind in zip(params["tail"], cache["tail"], tail_kinds):
         x, a, c = layer_forward(p_l, cfg, kind, x, positions, cache=c_l)
         new_tail.append(c)
-    logits = _unembed(params, cfg, x[:, -1:])
-    new_cache = {"pos": cache["pos"] + S, "cycles": new_cycles,
+    if length is None:
+        length = S
+    last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
+    logits = _unembed(params, cfg, last)
+    new_cache = {"pos": cache["pos"] + length, "cycles": new_cycles,
                  "tail": new_tail}
     return logits[:, 0], new_cache
 
@@ -534,12 +545,13 @@ _STACKED_KEYS = ("cycles", "self", "cross")   # leading dim = layer stack
 
 
 def cache_take_slot(cache: Dict[str, Any], slot: int) -> Dict[str, Any]:
-    """Length-1 batch view of one slot of a decode cache."""
+    """Length-1 batch view of one slot of a decode cache (`slot` may be
+    traced)."""
     out = {}
     for k, v in cache.items():
         ax = 1 if k in _STACKED_KEYS else 0
         out[k] = jax.tree_util.tree_map(
-            lambda t: jax.lax.slice_in_dim(t, slot, slot + 1, axis=ax), v)
+            lambda t: jax.lax.dynamic_slice_in_dim(t, slot, 1, axis=ax), v)
     return out
 
 

@@ -60,6 +60,72 @@ def test_engine_slot_reuse_and_concurrency():
     assert reqs[0].tokens == reqs[1].tokens == reqs[2].tokens
 
 
+def test_concurrent_admissions_and_steps_keep_every_request_exact():
+    """Clients admit from their own threads while a server thread steps
+    the engine, with the interpreter switching threads often: every
+    request still gets the model's own greedy tokens (an admission and
+    a decode step each read and replace the whole cache)."""
+    import sys
+    import threading
+    cfg = configs.get_smoke("qwen2.5-3b")
+    params = MDL.init_params(jax.random.PRNGKey(0), cfg)
+    eng = InferenceEngine(cfg, params, batch_slots=3, max_len=48)
+    eng.warmup()
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab_size, n, np.int32)
+               for n in (5, 9, 13) * 4]
+    new_tokens = 6
+
+    def reference(prompt):
+        cache = MDL.init_cache(cfg, 1, 48)
+        logits, cache = prefill(params, cache, jnp.asarray(prompt)[None])
+        toks = [int(jnp.argmax(logits[0]))]
+        for _ in range(new_tokens):
+            logits, cache = decode(params, cache,
+                                   jnp.asarray([toks[-1]], jnp.int32))
+            toks.append(int(jnp.argmax(logits[0])))
+        return toks
+
+    prefill = jax.jit(lambda p, c, t: MDL.prefill(p, cfg, t, c))
+    decode = jax.jit(lambda p, c, t: MDL.decode_step(p, cfg, t, c))
+    want = [reference(p) for p in prompts]
+    reqs = [Request(id=f"c{i}", prompt=p, max_new_tokens=new_tokens)
+            for i, p in enumerate(prompts)]
+    stop = threading.Event()
+
+    def serve():
+        while not stop.is_set():
+            if not eng.step():
+                time.sleep(0)
+
+    def client(mine):
+        for r in mine:
+            while not eng.try_admit(r):
+                time.sleep(0.0005)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        server = threading.Thread(target=serve)
+        clients = [threading.Thread(target=client, args=(reqs[k::4],))
+                   for k in range(4)]
+        server.start()
+        for t in clients:
+            t.start()
+        for t in clients:
+            t.join(timeout=120)
+        deadline = time.monotonic() + 120
+        while eng.active_count() and time.monotonic() < deadline:
+            time.sleep(0.01)
+    finally:
+        stop.set()
+        sys.setswitchinterval(old)
+    server.join(timeout=30)
+    assert not server.is_alive()
+    assert not any(t.is_alive() for t in clients)
+    assert [r.tokens for r in reqs] == want
+
+
 def test_router_epoch_and_push():
     r = Router()
     seen = []

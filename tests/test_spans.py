@@ -120,8 +120,9 @@ def test_engine_spans_admission_and_decode():
                       "engine.decode", "engine.sync"})
     admit, first = got[0], got[1]
     assert (admit.name, first.name) == ("engine.admit", "engine.first_token")
-    assert admit.attrs == {"id": "spanned", "prompt_len": 8,
-                           "server": "s0", "rung": "m:full"}
+    # max_len 48 is the one bucket; warmup() never ran
+    assert admit.attrs == {"id": "spanned", "prompt_len": 8, "bucket": 48,
+                           "warm": False, "server": "s0", "rung": "m:full"}
     assert first.attrs == {"id": "spanned"} and first.parent == admit.id
     assert admit.start <= first.start <= first.end <= admit.end
     assert admit.end - admit.start > first.end - first.start

@@ -127,6 +127,29 @@ def test_full_width_prefill_compiles_for_v5e(one_chip, qwen):
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
+def test_full_width_admission_needs_no_more_than_its_prefill(one_chip,
+                                                             qwen):
+    """The engine's admission (slot ops, prefill and first token in one
+    program) on a one-slot cache, as the cells deploy it, needs no more
+    memory than the bare prefill of that slot: taking and putting the
+    slot fold away, so no second copy of the cache is held."""
+    from repro.models import model as MDL
+    from repro.serving.engine import _admit
+    cfg, params = qwen
+    cache = jax.eval_shape(partial(MDL.init_cache, cfg, 1, 4096))
+    prompt = jax.ShapeDtypeStruct((1, 1024), jnp.int32)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    admit = jax.jit(partial(_admit, cfg)).lower(
+        *_on(one_chip, (params, cache, prompt, scalar, scalar))).compile()
+    bare = jax.jit(lambda p, c, t: MDL.prefill(p, cfg, t, c)).lower(
+        *_on(one_chip, (params, cache, prompt))).compile()
+    a, b = admit.memory_analysis(), bare.memory_analysis()
+    # the slot and the length are scalars, each padded to a tile
+    assert a.argument_size_in_bytes <= b.argument_size_in_bytes + 4096
+    assert a.output_size_in_bytes <= b.output_size_in_bytes
+    assert a.temp_size_in_bytes <= b.temp_size_in_bytes
+
+
 def test_full_width_layer_init_fits_beside_its_output(one_chip, qwen):
     """`init_params` builds the 36-layer stack in one program whose
     scratch stays far below the stack it writes: no second copy."""
