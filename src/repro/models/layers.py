@@ -336,6 +336,38 @@ def decode_attention_jnp(q, k_cache, v_cache, cache_len, *, window=0):
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
+def decode_attention_append_jnp(q, k_cache, v_cache, valid, k_new, v_new):
+    """Single-token attention over a read-only KV cache and the token's
+    own K/V in one softmax: the attention of writing the token into the
+    cache and attending over it, with the cache left as it lies.
+
+    q: (B, 1, H, hd); k_cache/v_cache: (B, Smax, KVH, hd); valid:
+    (B, Smax) bool, the cache entries the token sees; k_new/v_new:
+    (B, KVH, hd). Grouped-KV einsums against the unexpanded cache, as in
+    `decode_attention_jnp`. The softmax's max and sum over the cache are
+    reductions, and the token's own score joins them after, so the
+    scores of a sequence-sharded cache are never gathered to one piece.
+    """
+    B, _, H, hd = q.shape
+    KVH = k_cache.shape[2]
+    G = H // KVH
+    scale = 1.0 / math.sqrt(hd)
+    qg = (q * jnp.asarray(scale, q.dtype))[:, 0].reshape(B, KVH, G, hd)
+    s = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache,
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
+    s_new = jnp.einsum("bkgd,bkd->bkg", qg, k_new,
+                       preferred_element_type=jnp.float32)[..., None]
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), s_new)
+    p = jnp.exp(s - m)
+    p_new = jnp.exp(s_new - m)
+    out = jnp.einsum("bkgs,bskd->bkgd", p.astype(v_cache.dtype), v_cache,
+                     preferred_element_type=jnp.float32)
+    out = ((out + p_new * v_new[:, :, None].astype(jnp.float32))
+           / (jnp.sum(p, axis=-1, keepdims=True) + p_new))
+    return out.reshape(B, 1, H, hd).astype(q.dtype)
+
+
 def init_cross_attention(key, cfg, dtype=None):
     """Cross-attention (whisper decoder): kv from encoder states."""
     return init_attention(key, cfg, dtype)

@@ -22,7 +22,7 @@ from repro.models import moe as M
 from repro.models import rglru as R
 from repro.models import rwkv6 as W
 from repro.models.config import ModelConfig
-from repro.parallel.sharding import logical_constraint
+from repro.parallel.sharding import current_mesh, logical_constraint
 
 ACT_SPEC = P(("pod", "data"), None, None)
 HEAD_SPEC = P(("pod", "data"), None, "model", None)
@@ -232,8 +232,19 @@ def _write_kv_prefill(cache, cfg, kind, k, v, positions):
     return {"k": knew, "v": vnew}
 
 
+def _decode_slot(kind, pos, cache_len):
+    """The cache entry the token at `pos` takes: a ring's slot for
+    `local`, the position itself otherwise."""
+    return (pos % cache_len) if kind == "local" else pos
+
+
 def layer_decode(params, cfg, kind, x, pos, cache):
-    """Single-token layer apply. x: (B,1,d); pos: (B,) absolute position."""
+    """Single-token layer apply. x: (B,1,d); pos: (B,) absolute position.
+
+    Returns (x, state): the whole new state of a recurrent layer; the
+    token's own {"k", "v"} (B, KVH, hd) of an attention layer, which
+    reads its cache as it lies and leaves the write to
+    `_write_kv_decode`."""
     if kind == "rwkv":
         h, tm_state = W.time_mix(params["tm"], cfg,
                                  L.layernorm(params["norm1"], x, cfg.norm_eps),
@@ -257,17 +268,14 @@ def layer_decode(params, cfg, kind, x, pos, cache):
     xn = L.rmsnorm(params["norm1"], x, cfg.norm_eps)
     q, k, v = _attn_common(params, cfg, kind, xn, pos[:, None])
     W_ = cache["k"].shape[1]
-    slot = (pos % W_) if kind == "local" else pos
-    # one-hot masked write instead of a scatter: GSPMD handles the
-    # elementwise select shard-locally on the (batch, seq)-sharded cache,
-    # where a scatter forced a full-cache regather (measured: dominant
-    # collective term of the decode cells).
-    onehot = (jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], W_), 1)
-              == slot[:, None])[..., None, None]
-    knew = jnp.where(onehot, k[:, 0][:, None], cache["k"])
-    vnew = jnp.where(onehot, v[:, 0][:, None], cache["v"])
-    filled = jnp.minimum(pos + 1, W_)
-    o = L.decode_attention_jnp(q, knew, vnew, filled)
+    # the entries written before this token, less the ring slot it is
+    # about to take (that token has left the window); the token's own
+    # K/V join the softmax as one more score and value
+    idx = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], W_), 1)
+    valid = ((idx < jnp.minimum(pos, W_)[:, None])
+             & (idx != _decode_slot(kind, pos, W_)[:, None]))
+    o = L.decode_attention_append_jnp(q, cache["k"], cache["v"], valid,
+                                      k[:, 0], v[:, 0])
     o = jnp.einsum("bshk,hkd->bsd", o, params["attn"]["wo"])
     x = x + o
 
@@ -277,7 +285,43 @@ def layer_decode(params, cfg, kind, x, pos, cache):
     else:
         h = L.swiglu(params["ffn"], xn)
     x = x + h
-    return x, {"k": knew, "v": vnew}
+    return x, {"k": k[:, 0], "v": v[:, 0]}
+
+
+def _write_kv_decode(kind, cache, new, pos):
+    """A layer's cache after a decode step, from what `layer_decode`
+    returned: a recurrent state replaces the old one whole; each slot's
+    new token {"k", "v"} (..., B, KVH, hd) goes into the K/V cache
+    (..., B, S, KVH, hd) at its `_decode_slot`, for every layer of a
+    leading layer dim at once."""
+    if kind in ("rwkv", "rglru"):
+        return new
+    S = cache["k"].shape[-3]
+    slot = _decode_slot(kind, pos, S)
+    if current_mesh() is not None:
+        # one-hot masked write instead of a scatter: GSPMD handles the
+        # elementwise select shard-locally on the (batch, seq)-sharded
+        # cache, where a scatter forced a full-cache regather (measured:
+        # dominant collective term of the decode cells).
+        onehot = (jax.lax.broadcasted_iota(jnp.int32, (pos.shape[0], S), 1)
+                  == slot[:, None])[..., None, None]
+        return {n: jnp.where(onehot, new[n][..., None, :, :], cache[n])
+                for n in ("k", "v")}
+    # one position per slot, in place where the cache is donated; a slot
+    # past the cache (a free one, which admission resets) is clamped to
+    # the last entry, which it does not read before rewriting it
+    out = {}
+    for n in ("k", "v"):
+        c = cache[n]
+        bax = c.ndim - 4
+        for b in range(pos.shape[0]):
+            start = [0] * c.ndim
+            start[bax], start[bax + 1] = b, slot[b]
+            row = jax.lax.slice_in_dim(new[n], b, b + 1, axis=bax)
+            c = jax.lax.dynamic_update_slice(
+                c, row[..., None, :, :].astype(c.dtype), start)
+        out[n] = c
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -509,26 +553,29 @@ def decode_step(params, cfg: ModelConfig, tokens, cache):
     x = _embed_inputs(params, cfg, tokens[:, None])
     pat = cfg.block_pattern
 
+    # the layer scan reads the stacked caches and writes none: its ys are
+    # each layer's new token (or recurrent state), written after it
     def body(x, xs):
         cycle_params, cycle_cache = xs
-        new_caches = []
+        new = []
         for i, kind in enumerate(pat):
             x, c = layer_decode(cycle_params[i], cfg, kind, x, pos,
                                 cycle_cache[i])
-            new_caches.append(c)
-        return x, tuple(new_caches)
+            new.append(c)
+        return x, tuple(new)
 
     new_cycles = []
     if params["cycles"]:
         x, ys = jax.lax.scan(body, x, (tuple(params["cycles"]),
                                        tuple(cache["cycles"])))
-        new_cycles = list(ys)
+        new_cycles = [_write_kv_decode(kind, c, y, pos)
+                      for kind, c, y in zip(pat, cache["cycles"], ys)]
     kinds = cfg.layer_kinds()
     tail_kinds = kinds[len(kinds) - len(params["tail"]):]
     new_tail = []
     for p_l, c_l, kind in zip(params["tail"], cache["tail"], tail_kinds):
-        x, c = layer_decode(p_l, cfg, kind, x, pos, c_l)
-        new_tail.append(c)
+        x, y = layer_decode(p_l, cfg, kind, x, pos, c_l)
+        new_tail.append(_write_kv_decode(kind, c_l, y, pos))
     logits = _unembed(params, cfg, x)
     new_cache = {"pos": pos + 1, "cycles": new_cycles, "tail": new_tail}
     return logits[:, 0], new_cache

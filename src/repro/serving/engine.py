@@ -10,6 +10,9 @@ The engine's programs are a fixed set: one admission per prompt bucket
 its sampling. `warmup()` builds the set through the helpers serving
 calls, in the same JAX context, so a warm engine's first request
 traces, lowers and compiles nothing.
+
+The decode step is given the cache to consume (donated): it writes one
+position per slot in place, and no one reads the cache it was given.
 """
 
 from __future__ import annotations
@@ -112,11 +115,15 @@ class InferenceEngine:
         self.slots: List[Optional[Request]] = [None] * batch_slots
         self.remaining: np.ndarray = np.zeros(batch_slots, np.int32)
         self._lock = threading.Lock()
+        # the cache's largest leaf, whose deletion shows that a decode
+        # step consumed the cache it was given
+        sizes = [x.nbytes for x in jax.tree_util.tree_leaves(cache)]
+        self._probe = sizes.index(max(sizes))
 
         # the benchmark finds the decode program in a device trace by its
         # module name, `jit__lambda`
         self._decode = jax.jit(
-            lambda p, c, t: MDL.decode_step(p, cfg, t, c))
+            lambda p, c, t: MDL.decode_step(p, cfg, t, c), donate_argnums=1)
         self._admit = jax.jit(functools.partial(_admit, cfg))
 
     def _on_device(self):
@@ -151,7 +158,8 @@ class InferenceEngine:
         the paper's Fig. 2b load+warmup analogue): an admission per
         prompt bucket, or of WARM_PROMPT tokens where prompts keep their
         exact length, then a decode step and its sampling. They run
-        through serving's own helpers and leave the cache as it was."""
+        through serving's own helpers and leave the cache as it was: the
+        decode consumes a copy."""
         lengths = self.buckets or (
             () if self.cfg.is_encoder_decoder else (WARM_PROMPT,))
         with self._on_device():
@@ -160,8 +168,8 @@ class InferenceEngine:
                                          np.zeros(n, np.int32))
                 int(first)
                 self._built.add(n)
-            logits, _ = self._decode_step(self.cache,
-                                          [0] * self.batch_slots)
+            scratch = jax.tree_util.tree_map(jnp.copy, self.cache)
+            logits, _ = self._decode_step(scratch, [0] * self.batch_slots)
             self._sample(logits)
 
     # -- admission -----------------------------------------------------------
@@ -195,8 +203,11 @@ class InferenceEngine:
             last = [r.tokens[-1] if r is not None else 0
                     for r in self.slots]
             ids = tuple(self.slots[i].id for i in active)
-            with self._on_device(), spans.span("engine.decode", ids=ids):
+            probe = jax.tree_util.tree_leaves(self.cache)[self._probe]
+            with self._on_device(), spans.span("engine.decode",
+                                               ids=ids) as sp:
                 logits, self.cache = self._decode_step(self.cache, last)
+                sp.attrs["donated"] = probe.is_deleted()
         with self._on_device(), spans.span("engine.sync", ids=ids):
             nxt = self._sample(logits)
         finished = []
@@ -213,8 +224,9 @@ class InferenceEngine:
 
     def device_bytes(self) -> int:
         """Bytes this engine holds on its device: params plus cache."""
-        return sum(x.nbytes for x in
-                   jax.tree_util.tree_leaves((self.params, self.cache)))
+        with self._lock:
+            return sum(x.nbytes for x in
+                       jax.tree_util.tree_leaves((self.params, self.cache)))
 
     def active_count(self) -> int:
         with self._lock:

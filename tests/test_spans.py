@@ -130,7 +130,8 @@ def test_engine_spans_admission_and_decode():
     steps = got[2:]
     assert [s.name for s in steps] == ["engine.decode", "engine.sync"] * 3
     for dec, syn in zip(steps[::2], steps[1::2]):
-        assert dec.attrs == syn.attrs == {"ids": ("spanned",)}
+        assert dec.attrs == {"ids": ("spanned",), "donated": True}
+        assert syn.attrs == {"ids": ("spanned",)}
         assert dec.end <= syn.start
     assert steps[-1].end <= req.done_at
     # tokens are the model's own greedy prefill + decode

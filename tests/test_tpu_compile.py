@@ -163,3 +163,32 @@ def test_full_width_layer_init_fits_beside_its_output(one_chip, qwen):
                 for x in jax.tree_util.tree_leaves(params["cycles"]))
     assert stack <= mem.output_size_in_bytes < 1.01 * stack   # tile pad
     assert mem.temp_size_in_bytes < 0.25 * stack
+
+
+def test_full_width_decode_writes_one_position_in_place(one_chip):
+    """qwen1.5-4b's decode step at `max_len` 4096, its cache donated as
+    the engine jits it: the output cache aliases the donated one, the
+    scratch stays below one layer's K+V, and no cache-shaped copy or
+    select is left (each layer's cache is read where it lies, and one
+    position is written per slot)."""
+    import re
+
+    from repro import configs
+    from repro.models import model as MDL
+    cfg = configs.get_config("qwen1.5-4b")
+    cache = jax.eval_shape(partial(MDL.init_cache, cfg, 1, 4096))
+    tok = jax.ShapeDtypeStruct((1,), jnp.int32)
+    step = jax.jit(lambda p, c, t: MDL.decode_step(p, cfg, t, c),
+                   donate_argnums=1)
+    compiled = step.lower(*_on(one_chip, (MDL.param_shapes(cfg), cache,
+                                          tok))).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(cache))
+    layer_kv = 2 * 4096 * cfg.num_kv_heads * cfg.head_dim * 2
+    assert cache_bytes > 1.6e9
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < layer_kv
+    cache_shaped = re.compile(
+        r"= bf16\[1,1,4096,20,128\]\{[^}]*\} (copy|select)\(")
+    assert not cache_shaped.findall(compiled.as_text())
